@@ -35,7 +35,8 @@ def route_histogram(stats) -> str:
     routes = np.asarray(stats.routes)[:iters]
     if iters == 0 or routes.max(initial=-1) < 0:
         return "[]"
-    counts = np.bincount(routes[routes >= 0], minlength=2)
+    # The low bit of a route code is the strategy (kernel codes fold in).
+    counts = np.bincount(routes[routes >= 0] % 2, minlength=2)
     return "[" + ";".join(str(int(c)) for c in counts[:2]) + "]"
 
 

@@ -22,6 +22,7 @@ from benchmarks import (bench_agg, bench_bandwidth, bench_chaos,
                         bench_frontend, bench_incremental, bench_kmeans,
                         bench_pagerank, bench_recovery, bench_rehash,
                         bench_scalability, bench_sssp, common)
+from repro.compile_cache import enable_compile_cache
 
 SUITES = [
     ("fig4_agg", bench_agg),
@@ -67,6 +68,7 @@ def main():
     ap.add_argument("--artifact-dir", default="bench_artifacts",
                     help="where BENCH_<suite>.json artifacts are written")
     args = ap.parse_args()
+    enable_compile_cache()
     sel = [s for s in args.only.split(",") if s]
     failed = []
     for name, mod in SUITES:

@@ -10,6 +10,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.operators import (Table, apply_function, fk_join, group_by,
                                   select)
 from repro.core.optimizer import (best_udf_join_interleaving,
@@ -18,6 +19,7 @@ from repro.core.plan import (PlanNode, groupby, join, plan_runtime, rehash,
                              scan, udf)
 
 rng = np.random.default_rng(0)
+enable_compile_cache()
 N_EDGES, N_PAGES = 100_000, 4_096
 
 # ---- physical execution ---------------------------------------------------

@@ -11,10 +11,13 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.algorithms import pagerank
+from repro.compile_cache import enable_compile_cache
 from repro.core.partition import PartitionSnapshot
 from repro.data.graphs import load_dataset
 
 SHARDS = 8
+
+enable_compile_cache()
 
 n, graph = load_dataset("dbpedia-small", num_shards=SHARDS)
 snap = PartitionSnapshot(n_keys=n, num_shards=SHARDS)

@@ -12,6 +12,7 @@ import numpy as np
 
 from repro import frontend as F
 from repro.algorithms import sssp
+from repro.compile_cache import enable_compile_cache
 from repro.core.partition import PartitionSnapshot
 from repro.core.plan import plan_runtime
 from repro.data.graphs import DATASETS, load_dataset, make_powerlaw_graph
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--quick", action="store_true",
                     help="small dataset / fewer shards (CI smoke mode)")
     args = ap.parse_args()
+    enable_compile_cache()
     dataset = "dbpedia-small" if args.quick else "dbpedia"
     shards = 4 if args.quick else 8
 

@@ -8,10 +8,12 @@ buffer under sliding windows).
 """
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch import serve
 
 if __name__ == "__main__":
     sys.argv = [sys.argv[0], "--arch", "xlstm-350m", "--reduced",
                 "--batch", "4", "--prompt-len", "16",
                 "--new-tokens", "24"] + sys.argv[1:]
+    enable_compile_cache()
     serve.main()

@@ -16,11 +16,13 @@ import tempfile
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.data.graphs import make_powerlaw_graph
 from repro.incremental import (EdgeDelete, EdgeInsert, PointInsert,
                                PointRemove, ViewManager)
 
 rng = np.random.default_rng(0)
+enable_compile_cache()
 N = 2_048
 TICKS = 5
 

@@ -9,6 +9,7 @@ sized to run on CPU; on a pod, drop --reduced and set --mesh.
 """
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch import train
 
 if __name__ == "__main__":
@@ -17,4 +18,5 @@ if __name__ == "__main__":
                 "--global-batch", "16", "--lr", "3e-3",
                 "--ckpt-every", "100",
                 "--compression", "delta"] + sys.argv[1:]
+    enable_compile_cache()
     train.main()

@@ -18,6 +18,7 @@ does O(|Δᵢ| edges) work and moves O(|Δᵢ|) bytes per stratum.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -43,6 +44,9 @@ def current_pr(state: PRState) -> jax.Array:
     return BASE + DAMPING * state.acc
 
 
+# Memoized: equal arguments give the same algorithm object, so a repeated
+# run() with equal settings reuses the compiled fixpoint loop.
+@functools.lru_cache(maxsize=64)
 def make_algorithm(snapshot: PartitionSnapshot, threshold: float = 1e-3,
                    src_capacity: int = 1024, edge_capacity: int = 16384
                    ) -> DeltaAlgorithm:

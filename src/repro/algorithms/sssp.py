@@ -15,6 +15,7 @@ behaviour even with relation-level Δ updates the paper grants them).
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -35,6 +36,9 @@ class SPState(NamedTuple):
     sent: jax.Array  # f32[block] — distance last propagated (inf = never)
 
 
+# Memoized: equal arguments give the same algorithm object, so a repeated
+# run() with equal settings reuses the compiled fixpoint loop.
+@functools.lru_cache(maxsize=64)
 def make_algorithm(snapshot: PartitionSnapshot, src_capacity: int = 1024,
                    edge_capacity: int = 16384) -> DeltaAlgorithm:
     block = snapshot.block_size

@@ -13,8 +13,10 @@ from repro.core.delta import (ANN_ADJUST, ANN_DELETE, ANN_INSERT, ANN_REPLACE,
                               PAD_KEY, DeltaBuffer, combine_route,
                               combine_route_scatter)
 from repro.core.engine import CapacityTier, DeltaAlgorithm, ShardedExecutor
-from repro.core.fixpoint import (ROUTE_SCATTER, ROUTE_SORT, FixpointResult,
-                                 StratumOutcome, StratumStats, run_strata,
+from repro.core.fixpoint import (ROUTE_SCATTER, ROUTE_SCATTER_KERNEL,
+                                 ROUTE_SORT, ROUTE_SORT_KERNEL,
+                                 FixpointResult, StratumOutcome,
+                                 StratumStats, run_strata,
                                  with_explicit_condition)
 from repro.core.handlers import BUILTIN_UDAS, Aggregator
 from repro.core.partition import PartitionSnapshot
@@ -23,7 +25,8 @@ __all__ = [
     "ANN_ADJUST", "ANN_DELETE", "ANN_INSERT", "ANN_REPLACE", "PAD_KEY",
     "DeltaBuffer", "combine_route", "combine_route_scatter", "CapacityTier",
     "DeltaAlgorithm", "ShardedExecutor", "FixpointResult",
-    "ROUTE_SORT", "ROUTE_SCATTER",
+    "ROUTE_SORT", "ROUTE_SCATTER", "ROUTE_SORT_KERNEL",
+    "ROUTE_SCATTER_KERNEL",
     "StratumOutcome", "StratumStats", "run_strata",
     "with_explicit_condition", "BUILTIN_UDAS", "Aggregator",
     "PartitionSnapshot",

@@ -36,8 +36,9 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import delta as deltamod
 from repro.core.delta import PAD_KEY, DeltaBuffer
-from repro.core.fixpoint import (ROUTE_SCATTER, ROUTE_SORT, FixpointResult,
-                                 StratumOutcome, run_strata,
+from repro.core.fixpoint import (ROUTE_SCATTER, ROUTE_SCATTER_KERNEL,
+                                 ROUTE_SORT, ROUTE_SORT_KERNEL,
+                                 FixpointResult, StratumOutcome, run_strata,
                                  with_explicit_condition)
 from repro.core.partition import PartitionSnapshot
 
@@ -93,18 +94,30 @@ def _dense_combine(stacked: jax.Array, combiner: str, axis: int) -> jax.Array:
     raise ValueError(combiner)
 
 
-def _shard_map_compat(body, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (new API, check_vma) with fallback to
-    ``jax.experimental.shard_map`` (old API, check_rep) — one shim for
-    every shard_map entry point in the engine."""
-    try:
-        from jax import shard_map as _shard_map
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as _shard_map
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def _shard_map(body, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the engine's settings (replication is not
+    checked: stratum outcomes are made uniform by explicit collectives)."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def _over_shards(fn, *args, in_axes=None):
+    """Apply shard-local ``fn`` to each shard of the leading [S] axis of
+    its arguments, one shard after another (``lax.map``); ``in_axes``
+    marks with None the arguments every shard shares.  Shard-local code
+    then compiles as the 1-D program it is written as, the same one each
+    device runs under shard_map: batched (vmapped) gathers and scatters
+    over large arrays take the TPU compiler many times longer."""
+    if in_axes is None:
+        in_axes = (0,) * len(args)
+    mapped = tuple(a for a, ax in zip(args, in_axes) if ax == 0)
+
+    def one(xs):
+        it = iter(xs)
+        return fn(*[next(it) if ax == 0 else a
+                    for a, ax in zip(args, in_axes)])
+
+    return jax.lax.map(one, mapped)
 
 
 # Per-device views under shard_map keep a length-1 leading shard axis;
@@ -159,7 +172,10 @@ class ShardedExecutor:
     ``use_pallas_route`` dispatches the per-shard local rehash to the
     Pallas kernels (``kernels/delta_route`` for sort-strategy routing,
     ``kernels/scatter_route`` for the scatter strategy) — interpret mode
-    on CPU, compiled on TPU — instead of the jnp implementations.
+    on CPU, compiled on TPU — instead of the jnp implementations, on
+    every rung within the kernel's bounds.  A rung beyond them, or a
+    combiner the kernel lacks, routes through jnp; each stratum's
+    ``route`` code records which implementation ran (``route_code``).
 
     Observability: an attached ``tracer`` (``repro.obs.Tracer``) records a
     per-stratum probe from inside the compiled loop —
@@ -283,70 +299,78 @@ class ShardedExecutor:
     # ------------------------------------------------------------------
     # Sparse rehash (fused combine + route).
     # ------------------------------------------------------------------
+    def route_code(self, tier: CapacityTier, combiner: Optional[str]) -> int:
+        """Rehash implementation of a rung, fixed at trace time.
+
+        The strategy comes from :meth:`pick_route_strategy`.  With
+        ``use_pallas_route`` the rung runs that strategy's Pallas kernel
+        when its segment capacity, the snapshot and the combiner are
+        within the kernel's bounds, and the jnp implementation otherwise;
+        the returned code (``ROUTE_*`` in ``core/fixpoint.py``) is what
+        the stratum records."""
+        strategy = self.pick_route_strategy(tier.edge, combiner)
+        if strategy == "scatter":
+            if self.use_pallas_route:
+                from repro.kernels.scatter_route import scatter_route_fits
+                if scatter_route_fits(self.snapshot, tier.seg, combiner):
+                    return ROUTE_SCATTER_KERNEL
+            return ROUTE_SCATTER
+        if self.use_pallas_route:
+            from repro.kernels.delta_route import route_fits
+            if route_fits(tier.seg, self.snapshot.padded_keys):
+                return ROUTE_SORT_KERNEL
+        return ROUTE_SORT
+
     def _route_one(self, db: DeltaBuffer, seg_capacity: int,
-                   combiner: Optional[str], strategy: str = "sort"
+                   combiner: Optional[str], route: int = ROUTE_SORT
                    ) -> DeltaBuffer:
         """Local half of the rehash: one shard's outgoing Δ -> per-owner
         segments.  With a composable ``combiner`` this is the FUSED
-        combine-route — ``strategy`` picks the physical implementation
-        (one lexicographic sort on (owner, key) vs the sort-free
-        scatter-slab); without a combiner it is plain stable routing."""
+        combine-route — ``route`` picks the implementation (one
+        lexicographic sort on (owner, key), the sort-free scatter-slab,
+        or the Pallas kernel of either); without a combiner it is plain
+        stable routing."""
         S = self.snapshot.num_shards
+        if route == ROUTE_SCATTER_KERNEL:
+            from repro.kernels import interpret_mode
+            from repro.kernels.scatter_route import scatter_route_deltas
+            return scatter_route_deltas(
+                db, self.snapshot.owner_of(db.keys), S, seg_capacity,
+                combiner, snapshot=self.snapshot, interpret=interpret_mode())
+        if route == ROUTE_SORT_KERNEL:
+            # Kernel path: §5.2 pre-aggregation (jnp) + the Pallas
+            # routing kernel — property-tested equal to the fused
+            # single-sort combine_route.
+            from repro.core.handlers import pre_aggregate
+            from repro.kernels import interpret_mode
+            from repro.kernels.delta_route import route_deltas
+            if combiner is not None:
+                db = pre_aggregate(db, combiner)
+            return route_deltas(db, self.snapshot.owner_of(db.keys), S,
+                                seg_capacity,
+                                max_key=self.snapshot.padded_keys,
+                                interpret=interpret_mode())
         owners = self.snapshot.owner_of(db.keys)
-        # Interpret-mode Pallas everywhere except a real TPU backend —
-        # the "interpret on CPU, compiled on TPU" dispatch contract.
-        interp = jax.default_backend() != "tpu"
-        if strategy == "scatter" and combiner is not None:
-            if self.use_pallas_route:
-                from repro.kernels.scatter_route import scatter_route_deltas
-                return scatter_route_deltas(db, owners, S, seg_capacity,
-                                            combiner,
-                                            snapshot=self.snapshot,
-                                            interpret=interp)
+        if route == ROUTE_SCATTER:
             return deltamod.combine_route_scatter(
                 db, owners, S, seg_capacity, combiner,
                 snapshot=self.snapshot)
         if combiner is not None:
-            if self.use_pallas_route:
-                # Kernel path: §5.2 pre-aggregation (jnp) + the Pallas
-                # routing kernel — property-tested equal to the fused
-                # single-sort combine_route.
-                from repro.core.handlers import pre_aggregate
-                from repro.kernels.delta_route import route_deltas
-                agg = pre_aggregate(db, combiner)
-                agg_owners = self.snapshot.owner_of(agg.keys)
-                return route_deltas(agg, agg_owners, S, seg_capacity,
-                                    max_key=self.snapshot.padded_keys,
-                                    interpret=interp)
             return deltamod.combine_route(db, owners, S, seg_capacity,
                                           combiner)
-        if self.use_pallas_route:
-            from repro.kernels.delta_route import route_deltas
-            return route_deltas(db, owners, S, seg_capacity,
-                                max_key=self.snapshot.padded_keys,
-                                interpret=interp)
         return deltamod.route_by_owner(db, owners, S, seg_capacity)
 
     def rehash_sparse_simulated(self, stacked: DeltaBuffer,
                                 seg_capacity: Optional[int] = None,
                                 combiner: Optional[str] = None,
-                                strategy: str = "sort"
+                                route: int = ROUTE_SORT
                                 ) -> tuple[DeltaBuffer, jax.Array]:
         """stacked: [S] leading axis of per-shard outgoing Δ -> (incoming Δ,
         globally-summed routed delta count)."""
         S = self.snapshot.num_shards
         cap = self.seg_capacity if seg_capacity is None else seg_capacity
-        if self.use_pallas_route:
-            # pallas_call inside vmap is fragile in interpret mode: route
-            # each shard's buffer explicitly (S is small and static).
-            parts = [self._route_one(
-                jax.tree.map(lambda x, i=i: x[i], stacked), cap, combiner,
-                strategy) for i in range(S)]
-            routed = jax.tree.map(lambda *xs: jnp.stack(xs), *parts)
-        else:
-            routed = jax.vmap(
-                lambda db: self._route_one(db, cap, combiner,
-                                           strategy))(stacked)
+        routed = _over_shards(
+            lambda db: self._route_one(db, cap, combiner, route), stacked)
         keys = routed.keys.reshape(S, S, cap)             # [src, dst, cap]
         payload = routed.payload.reshape(S, S, cap, -1)
         ann = routed.ann.reshape(S, S, cap)
@@ -369,11 +393,11 @@ class ShardedExecutor:
     def rehash_sparse_shard_map(self, db: DeltaBuffer,
                                 seg_capacity: Optional[int] = None,
                                 combiner: Optional[str] = None,
-                                strategy: str = "sort"
+                                route: int = ROUTE_SORT
                                 ) -> tuple[DeltaBuffer, jax.Array]:
         S = self.snapshot.num_shards
         cap = self.seg_capacity if seg_capacity is None else seg_capacity
-        routed = self._route_one(db, cap, combiner, strategy)
+        routed = self._route_one(db, cap, combiner, route)
         keys = jax.lax.all_to_all(routed.keys.reshape(S, cap),
                                   self.axis_name, 0, 0, tiled=False)
         payload = jax.lax.all_to_all(
@@ -418,28 +442,80 @@ class ShardedExecutor:
             max_iters: int, mode: str = "delta",
             explicit_cond: Optional[Callable] = None) -> FixpointResult:
         """state0 / immutable carry a leading [S] shard axis in BOTH
-        backends (shard_map splits that axis across devices)."""
+        backends (shard_map splits that axis across devices).
+
+        The fixpoint is one jitted computation taking the state, the live
+        count and the immutable set as arguments: a second call with the
+        same shapes and the same executor, algorithm, mode, ``max_iters``
+        and ``explicit_cond`` reuses the compiled loop."""
         if mode not in ("delta", "nodelta"):
             raise ValueError(mode)
+        self._check_backend()
         if self.tracer is not None:
             # Anchor shard timelines at dispatch so the first stratum's
             # measured duration excludes host setup (eager calls; under
             # an enclosing jit this runs once at trace time, which only
             # shifts the first measured stratum).
             self.tracer.mark_shards(self.snapshot.num_shards)
+        return _fixpoint(
+            state0, jnp.asarray(live0, jnp.int32), immutable,
+            executor=self,
+            observers=(_ByIdentity(self.tracer),
+                       _ByIdentity(self.route_table)),
+            algo=algo, mode=mode, max_iters=max_iters,
+            explicit_cond=explicit_cond)
+
+    def precompile(self, algo: DeltaAlgorithm, state0, immutable,
+                   max_iters: int, mode: str = "delta",
+                   explicit_cond: Optional[Callable] = None):
+        """Compile the fixpoint loop :meth:`run` would run for these
+        arguments (arrays or ``jax.ShapeDtypeStruct``s), without running
+        it; a later ``run`` with equal shapes and settings reuses the
+        executable.  Independent loops can be compiled from several
+        threads at once.  Returns the ``jax.stages.Compiled``."""
+        if mode not in ("delta", "nodelta"):
+            raise ValueError(mode)
+        self._check_backend()
+        return _fixpoint.lower(
+            state0, jax.ShapeDtypeStruct((), jnp.int32), immutable,
+            executor=self,
+            observers=(_ByIdentity(self.tracer),
+                       _ByIdentity(self.route_table)),
+            algo=algo, mode=mode, max_iters=max_iters,
+            explicit_cond=explicit_cond).compile()
+
+    def _fixpoint_loop(self, algo: DeltaAlgorithm, state0, live0,
+                       immutable, max_iters: int, mode: str,
+                       explicit_cond: Optional[Callable]) -> FixpointResult:
+        """Traced body of :meth:`run`."""
         if self.backend == "simulated":
             stratum_fn = self._stratum_simulated(algo, immutable, mode)
-        elif self.backend == "shard_map":
-            stratum_fn = self._stratum_shard_map(algo, mode)
         else:
-            raise ValueError(self.backend)
+            stratum_fn = self._stratum_shard_map(algo, mode)
         if explicit_cond is not None:
             stratum_fn = with_explicit_condition(stratum_fn, explicit_cond)
         if self.backend == "shard_map":
             return self._run_shard_map_loop(stratum_fn, state0, live0,
                                             immutable, max_iters)
-        return run_strata(stratum_fn, state0, jnp.asarray(live0, jnp.int32),
-                          max_iters, tracer=self.tracer)
+        return run_strata(stratum_fn, state0, live0, max_iters,
+                          tracer=self.tracer)
+
+    def _check_backend(self) -> None:
+        """Refuse an unknown backend, and a shard_map run whose shard
+        count differs from the mesh axis: each device holds exactly one
+        shard of the leading [S] axis."""
+        if self.backend == "simulated":
+            return
+        if self.backend != "shard_map":
+            raise ValueError(self.backend)
+        if self.mesh is None:
+            raise ValueError("backend='shard_map' needs a mesh")
+        size = self.mesh.shape[self.axis_name]
+        if size != self.snapshot.num_shards:
+            raise ValueError(
+                f"snapshot has {self.snapshot.num_shards} shards but mesh "
+                f"axis {self.axis_name!r} has {size} devices; shard_map "
+                "places exactly one shard on each device")
 
     # ------------------------------------------------------------------
     # Resume-from-state (incremental view maintenance).
@@ -447,9 +523,25 @@ class ShardedExecutor:
     def live_count(self, algo: DeltaAlgorithm, state, immutable) -> jax.Array:
         """Globally-reduced |Δ₀| of ``state``: how many keys would refine if
         the fixpoint were (re)entered right now.  This is the seed live
-        count for :meth:`resume`."""
-        active, _ = jax.vmap(algo.active_fn)(state, immutable)
-        return jnp.sum(active.astype(jnp.int32))
+        count for :meth:`resume`.  Under shard_map each device counts its
+        own shard and a ``psum`` reduces, so state and graph keep the one
+        sharding they have."""
+        self._check_backend()
+        return _live_count(state, immutable, executor=self, algo=algo)
+
+    def _live_count_body(self, algo: DeltaAlgorithm, state, immutable):
+        if self.backend == "simulated":
+            active, _ = _over_shards(algo.active_fn, state, immutable)
+            return jnp.sum(active.astype(jnp.int32))
+
+        def count(st, imm):
+            active, _ = algo.active_fn(_squeeze(st), _squeeze(imm))
+            return jax.lax.psum(jnp.sum(active.astype(jnp.int32)),
+                                self.axis_name)
+
+        spec = P(self.axis_name)
+        return _shard_map(count, self.mesh, in_specs=(spec, spec),
+                          out_specs=P())(state, immutable)
 
     def resume(self, algo: DeltaAlgorithm, warm_state, immutable,
                max_iters: int, mode: str = "delta",
@@ -479,27 +571,19 @@ class ShardedExecutor:
                         explicit_cond: Optional[Callable] = None):
         """One-stratum function (state, idx) -> (state', outcome) for the
         stratum-sliced drivers (runtime/recovery.py) — identical semantics
-        to the fused while_loop, on BOTH backends: the simulated stratum
-        body directly, or one shard_map dispatch per stratum (same specs
-        as the fused loop, so a stratum-sliced run is bit-identical to
-        ``run`` stratum for stratum).
-
-        The simulated body is deliberately NOT wrapped in ``jax.jit``:
-        ``run`` executes its while_loop eagerly, and whole-stratum jit
-        changes float fusion (fma/reassociation) by ~1 ulp in
-        add-combining algorithms — the eager stratum body is what
-        reproduces ``run`` bit-for-bit, which recovery correctness tests
-        rely on.  The shard_map path IS jitted: its body is a single
-        compiled computation either way (bit-identical to the fused
-        shard_map loop, verified both ways), and eager shard_map
-        re-traces every call."""
+        to the fused while_loop, on BOTH backends: one jitted dispatch of
+        the simulated stratum body, or of the shard_map stratum (same
+        specs as the fused loop), so a stratum-sliced run is bit-identical
+        to ``run`` stratum for stratum.  The immutable set stays a runtime
+        argument, as in ``run``: closing the jit over it would bake the
+        graph into the computation as constants."""
+        self._check_backend()
         if self.backend == "simulated":
-            fn = self._stratum_simulated(algo, immutable, mode)
-            if explicit_cond is not None:
-                fn = with_explicit_condition(fn, explicit_cond)
-            return fn
-        if self.backend != "shard_map":
-            raise ValueError(self.backend)
+            observers = (_ByIdentity(self.tracer),
+                         _ByIdentity(self.route_table))
+            return lambda state, idx: _stratum_step(
+                state, immutable, idx, executor=self, observers=observers,
+                algo=algo, mode=mode, explicit_cond=explicit_cond)
         stratum = self._stratum_shard_map(algo, mode)
         if explicit_cond is not None:
             stratum = with_explicit_condition(stratum, explicit_cond)
@@ -510,12 +594,8 @@ class ShardedExecutor:
                 (_squeeze(state), _squeeze(imm)), idx)
             return _expand(new_state), outcome
 
-        # immutable stays a runtime argument (as in ``run``) — closing
-        # the jit over it would bake the full sharded graph into the
-        # traced computation as constants.
-        fn = jax.jit(_shard_map_compat(one, self.mesh,
-                                       in_specs=(spec, spec, P()),
-                                       out_specs=(spec, P())))
+        fn = jax.jit(_shard_map(one, self.mesh, in_specs=(spec, spec, P()),
+                                out_specs=(spec, P())))
         return lambda state, idx: fn(state, immutable, idx)
 
     # ------------------------------------------------------------------
@@ -579,23 +659,20 @@ class ShardedExecutor:
 
         def make_sparse_body(tier: CapacityTier, tier_idx: int):
             emit_fn = self._emit_fn(algo, tier)
-            # Physical rehash strategy is a per-rung trace-time constant:
-            # the Pregelix-style choice between sort- and scatter-based
-            # grouping, made from the rung's static capacities.
-            strategy = self.pick_route_strategy(tier.edge, combiner)
-            route_code = ROUTE_SCATTER if strategy == "scatter" \
-                else ROUTE_SORT
+            # Physical rehash implementation is a per-rung trace-time
+            # constant, made from the rung's static capacities.
+            route_code = self.route_code(tier, combiner)
 
             def sparse_body(state, stratum, active):
-                partial_state, outgoing = jax.vmap(
-                    emit_fn, in_axes=(0, 0, 0, None, 0))(
-                    state, immutable, active, stratum, shard_ids)
+                partial_state, outgoing = _over_shards(
+                    emit_fn, state, immutable, active, stratum, shard_ids,
+                    in_axes=(0, 0, 0, None, 0))
                 incoming, emitted = self.rehash_sparse_simulated(
                     outgoing, seg_capacity=tier.seg, combiner=combiner,
-                    strategy=strategy)
-                new_state, next_active = jax.vmap(
-                    algo.apply_sparse, in_axes=(0, 0, 0, None, 0))(
-                    partial_state, incoming, immutable, stratum, shard_ids)
+                    route=route_code)
+                new_state, next_active = _over_shards(
+                    algo.apply_sparse, partial_state, incoming, immutable,
+                    stratum, shard_ids, in_axes=(0, 0, 0, None, 0))
                 bytes_moved = emitted.astype(
                     jnp.float32) * algo.bytes_per_delta
                 return new_state, StratumOutcome(
@@ -608,13 +685,13 @@ class ShardedExecutor:
             return sparse_body
 
         def dense_body(state, stratum, active):
-            partial_state, contrib = jax.vmap(
-                algo.dense_emit, in_axes=(0, 0, None, 0))(
-                state, immutable, stratum, shard_ids)
+            partial_state, contrib = _over_shards(
+                algo.dense_emit, state, immutable, stratum, shard_ids,
+                in_axes=(0, 0, None, 0))
             incoming = self.rehash_dense_simulated(contrib, algo.combiner)
-            new_state, next_active = jax.vmap(
-                algo.apply_dense, in_axes=(0, 0, 0, None, 0))(
-                partial_state, incoming, immutable, stratum, shard_ids)
+            new_state, next_active = _over_shards(
+                algo.apply_dense, partial_state, incoming, immutable,
+                stratum, shard_ids, in_axes=(0, 0, 0, None, 0))
             n_padded = contrib.shape[1]
             bytes_moved = jnp.asarray(
                 S * n_padded * algo.payload_width * 4, jnp.float32)
@@ -622,8 +699,7 @@ class ShardedExecutor:
                 live_count=jnp.sum(next_active),
                 used_dense=jnp.asarray(True),
                 rehash_bytes=bytes_moved,
-                emitted=jnp.sum(jax.vmap(lambda a: jnp.sum(
-                    a.astype(jnp.int32)))(active)),
+                emitted=jnp.sum(active.astype(jnp.int32)),
                 tier=jnp.asarray(-1, jnp.int32),
                 route=jnp.asarray(-1, jnp.int32))
 
@@ -631,9 +707,9 @@ class ShardedExecutor:
         bodies.append(dense_body)
 
         def stratum(state, stratum_idx):
-            active, est_edges = jax.vmap(algo.active_fn)(state, immutable)
-            per_shard_src = jax.vmap(
-                lambda a: jnp.sum(a.astype(jnp.int32)))(active)
+            active, est_edges = _over_shards(algo.active_fn, state,
+                                             immutable)
+            per_shard_src = jnp.sum(active.astype(jnp.int32), axis=1)
             if mode == "nodelta":
                 new_state, outcome = dense_body(state, stratum_idx, active)
             else:
@@ -681,16 +757,14 @@ class ShardedExecutor:
                 emit_fn = self._emit_fn(algo, tier)
                 # Trace-time constant, identical on every shard (pure
                 # function of static rung capacities).
-                strategy = self.pick_route_strategy(tier.edge, combiner)
-                route_code = ROUTE_SCATTER if strategy == "scatter" \
-                    else ROUTE_SORT
+                route_code = self.route_code(tier, combiner)
 
                 def sparse_body(st):
                     partial_state, outgoing = emit_fn(
                         st, imm, active, stratum_idx, shard_id)
                     incoming, emitted = self.rehash_sparse_shard_map(
                         outgoing, seg_capacity=tier.seg, combiner=combiner,
-                        strategy=strategy)
+                        route=route_code)
                     new_state, next_active = algo.apply_sparse(
                         partial_state, incoming, imm, stratum_idx, shard_id)
                     return (new_state, imm), StratumOutcome(
@@ -750,21 +824,63 @@ class ShardedExecutor:
 
     def _run_shard_map_loop(self, stratum_fn, state0, live0, immutable,
                             max_iters):
-        def body(state, imm):
+        def body(state, imm, live):
             state, imm = _squeeze(state), _squeeze(imm)
-            res = run_strata(stratum_fn, (state, imm),
-                             jnp.asarray(live0, jnp.int32), max_iters)
+            res = run_strata(stratum_fn, (state, imm), live, max_iters)
             final_state, _ = res.state
             return FixpointResult(state=_expand(final_state),
                                   stats=res.stats)
 
         spec = P(self.axis_name)
-        fn = _shard_map_compat(body, self.mesh, in_specs=(spec, spec),
-                               out_specs=FixpointResult(state=spec,
-                                                        stats=P()))
-        res = fn(state0, immutable)
+        fn = _shard_map(body, self.mesh, in_specs=(spec, spec, P()),
+                        out_specs=FixpointResult(state=spec, stats=P()))
+        res = fn(state0, immutable, live0)
         if self.tracer is not None:
             # Fixpoint marker outside the shard_map (replicated stats —
             # one probe, not one per shard).
             self.tracer.fixpoint_probe(res.stats.iterations, max_iters)
         return res
+
+
+class _ByIdentity:
+    """Static jit-key wrapper equal only to a wrapper of the same object.
+
+    The executor's tracer and route table take no part in its equality,
+    yet they change the traced loop: keying the compiled loop on their
+    identity keeps executors that differ only there apart."""
+
+    __slots__ = ("obj",)
+
+    def __init__(self, obj):
+        self.obj = obj
+
+    def __hash__(self) -> int:
+        return id(self.obj)
+
+    def __eq__(self, other) -> bool:
+        return isinstance(other, _ByIdentity) and other.obj is self.obj
+
+
+@partial(jax.jit, static_argnames=("executor", "observers", "algo", "mode",
+                                   "max_iters", "explicit_cond"))
+def _fixpoint(state0, live0, immutable, *, executor, observers, algo, mode,
+              max_iters, explicit_cond) -> FixpointResult:
+    del observers  # part of the compile key only
+    return executor._fixpoint_loop(algo, state0, live0, immutable,
+                                   max_iters, mode, explicit_cond)
+
+
+@partial(jax.jit, static_argnames=("executor", "observers", "algo", "mode",
+                                   "explicit_cond"))
+def _stratum_step(state, immutable, idx, *, executor, observers, algo, mode,
+                  explicit_cond):
+    del observers  # part of the compile key only
+    fn = executor._stratum_simulated(algo, immutable, mode)
+    if explicit_cond is not None:
+        fn = with_explicit_condition(fn, explicit_cond)
+    return fn(state, idx)
+
+
+@partial(jax.jit, static_argnames=("executor", "algo"))
+def _live_count(state, immutable, *, executor, algo):
+    return executor._live_count_body(algo, state, immutable)

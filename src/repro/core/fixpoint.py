@@ -29,8 +29,13 @@ import jax
 import jax.numpy as jnp
 
 
-ROUTE_SORT = 0     # stratum rehash ran the sort-based combine-route
-ROUTE_SCATTER = 1  # stratum rehash ran the scatter-based combine-route
+# Rehash implementation a sparse stratum ran (``StratumStats.routes``).
+# The low bit is the strategy; codes >= ROUTE_SORT_KERNEL ran a Pallas
+# kernel (``ShardedExecutor.route_code``).
+ROUTE_SORT = 0            # sort-based combine-route (jnp)
+ROUTE_SCATTER = 1         # scatter-based combine-route (jnp)
+ROUTE_SORT_KERNEL = 2     # pre-aggregate + kernels/delta_route
+ROUTE_SCATTER_KERNEL = 3  # kernels/scatter_route
 
 
 class StratumStats(NamedTuple):
@@ -40,9 +45,9 @@ class StratumStats(NamedTuple):
     iterations: jax.Array     # int32[]            — strata actually executed
     tiers: jax.Array          # int32[max_iters]   — ladder rung per stratum
     #                           (0 = smallest sparse tier, -1 = dense / n.a.)
-    routes: jax.Array = None  # int32[max_iters]   — rehash strategy per
-    #                           stratum (ROUTE_SORT / ROUTE_SCATTER,
-    #                           -1 = dense / n.a.)
+    routes: jax.Array = None  # int32[max_iters]   — rehash implementation
+    #                           per stratum (ROUTE_* codes, -1 = dense /
+    #                           n.a.)
 
 
 class StratumOutcome(NamedTuple):
@@ -53,8 +58,7 @@ class StratumOutcome(NamedTuple):
     rehash_bytes: jax.Array  # float32[] — bytes the rehash moved
     emitted: jax.Array       # int32[]  — deltas emitted this stratum
     tier: jax.Array = -1     # int32[]  — capacity-ladder rung (-1 = dense)
-    route: jax.Array = -1    # int32[]  — ROUTE_SORT / ROUTE_SCATTER
-    #                           (-1 = dense / n.a.)
+    route: jax.Array = -1    # int32[]  — ROUTE_* code (-1 = dense / n.a.)
 
 
 class FixpointResult(NamedTuple):

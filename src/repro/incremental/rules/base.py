@@ -184,20 +184,17 @@ class GraphRuleBase(IncrementalRule):
                                    self.edge_capacity)
         self.resume_algo = self.make_algo(view, self.resume_src_capacity,
                                           self.resume_edge_capacity)
-        self._cold_fn = jax.jit(self.cold_impl)
-        self._resume_fn = jax.jit(
-            lambda st, g: self.resume_executor.resume(
-                self.resume_algo, st, g, self.max_iters, mode=self.mode))
 
     def make_algo(self, view, src_capacity: int, edge_capacity: int):
         raise NotImplementedError
 
     def cold_impl(self, graph):
-        """-> FixpointResult (traced; shapes pinned by the store)."""
+        """-> FixpointResult (shapes pinned by the store, so every refresh
+        reuses the executor's compiled loop)."""
         raise NotImplementedError
 
     def cold(self, view):
-        res = self._cold_fn(view.immutable)
+        res = self.cold_impl(view.immutable)
         return res.state, res
 
     def resume(self, view, state):
@@ -206,7 +203,9 @@ class GraphRuleBase(IncrementalRule):
         budget = getattr(view, "retry_budget", None)
         if self.resilient_root is None and fault_plan is None \
                 and retry is None and budget is None:
-            res = self._resume_fn(state, view.immutable)
+            res = self.resume_executor.resume(
+                self.resume_algo, state, view.immutable, self.max_iters,
+                mode=self.mode)
             return res.state, res
         import shutil
         import tempfile

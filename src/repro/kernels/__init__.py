@@ -18,4 +18,15 @@ validated in interpret mode over shape/dtype sweeps:
   kmeans_assign    — blocked point×centroid distances + argmin (MXU)
   flash_attention  — blocked online-softmax attention, GQA-aware (the LM
                      serving/training hot spot)
+
+The route kernels take ``interpret`` without a default; callers pass
+:func:`interpret_mode`, the one place that decides it.
 """
+import jax
+
+
+def interpret_mode() -> bool:
+    """Pallas interpret mode unless the default backend is a TPU: a
+    kernel runs compiled only on the chip it was written for."""
+    return jax.default_backend() != "tpu"
+

@@ -1,32 +1,34 @@
-"""Public op: delta-buffer routing with automatic padding + dispatch.
+"""Public op: delta-buffer routing through the Pallas kernel.
 
-``route_deltas(db, owners, num_shards, per_shard_capacity)`` pads the
-buffer to kernel-friendly shapes and calls the Pallas kernel
-(interpret-mode on CPU; compiled on TPU) — the same dispatch machinery as
-kernels/delta_scatter.  Falls back to the jnp oracle when the kernel's
-exactness bounds don't hold (num_shards >= 127 lanes, keys >= 2^24) or
-shapes degenerate.  The result matches ``core/delta.py:route_by_owner``
-slot-for-slot.
+``route_deltas(db, owners, num_shards, per_shard_capacity, max_key=...,
+interpret=...)`` pads the buffer to kernel-friendly shapes and calls the
+kernel.  It never substitutes the jnp oracle on its own: a call outside
+the kernel's bounds (``route_fits``) raises, and callers that want the
+oracle ask for it with ``use_kernel=False``.  The result matches
+``core/delta.py:route_by_owner`` slot-for-slot.
 """
 from __future__ import annotations
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.delta import PAD_KEY, DeltaBuffer
-from repro.kernels.delta_route.delta_route import (DEFAULT_CHUNK,
+from repro.kernels.delta_route.delta_route import (DEFAULT_CHUNK, MAX_CAP,
                                                    MAX_EXACT_KEY,
-                                                   OWNER_LANES, delta_route)
+                                                   delta_route)
 from repro.kernels.delta_route.ref import delta_route_ref
 from repro.kernels.pad import pad_to as _pad_to
 
 
+def route_fits(per_shard_capacity: int, max_key: int) -> bool:
+    """Whether the kernel takes segments of ``per_shard_capacity`` slots
+    for keys up to ``max_key``."""
+    return per_shard_capacity <= MAX_CAP and max_key <= MAX_EXACT_KEY
+
+
 def route_deltas(db: DeltaBuffer, owners: jax.Array, num_shards: int,
-                 per_shard_capacity: int, max_key: int = MAX_EXACT_KEY,
-                 use_kernel: bool = True, interpret: bool = True
-                 ) -> DeltaBuffer:
+                 per_shard_capacity: int, *, max_key: int, interpret: bool,
+                 use_kernel: bool = True) -> DeltaBuffer:
     """Bucket ``db`` into per-owner segments (route_by_owner contract).
 
     ``max_key``: largest key value the caller can produce — the kernel
@@ -34,10 +36,13 @@ def route_deltas(db: DeltaBuffer, owners: jax.Array, num_shards: int,
     """
     mask = db.keys != PAD_KEY
     owners = jnp.where(mask, owners, num_shards)
-    ok_kernel = (use_kernel and num_shards < OWNER_LANES
-                 and max_key <= MAX_EXACT_KEY)
     ann32 = db.ann.astype(jnp.int32)
-    if ok_kernel:
+    if use_kernel:
+        if not route_fits(per_shard_capacity, max_key):
+            raise ValueError(
+                f"delta_route takes per_shard_capacity <= {MAX_CAP} and "
+                f"keys <= {MAX_EXACT_KEY}; got {per_shard_capacity} and "
+                f"{max_key} — route this buffer with the jnp path")
         keys_p = _pad_to(db.keys, DEFAULT_CHUNK, -1)
         pay_p = _pad_to(db.payload, DEFAULT_CHUNK, 0.0)
         ann_p = _pad_to(ann32, DEFAULT_CHUNK, 0)

@@ -1,13 +1,13 @@
-"""Public op: sort-free combine-route with automatic padding + dispatch.
+"""Public op: sort-free combine-route through the Pallas kernel.
 
 ``scatter_route_deltas(db, owners, num_shards, per_shard_capacity,
-combiner, snapshot=...)`` pads the buffer to kernel-friendly shapes and
-calls the Pallas kernel (interpret-mode on CPU; compiled on TPU) — the
-same dispatch machinery as kernels/delta_route.  Falls back to the jnp
-oracle when the kernel's bounds don't hold (non-"add" combiners, hash
-partition scheme, block_size beyond the VMEM slab bound, cap·block
-beyond the finalize match-matrix bound, padded_keys ≥ 2^24) or shapes
-degenerate.  The result matches
+combiner, snapshot=..., interpret=...)`` pads the buffer to kernel-friendly
+shapes and calls the kernel.  It never substitutes the jnp oracle on its
+own: a call outside the kernel's bounds (``scatter_route_fits``: the
+"add" combiner, the block partition scheme, block_size within the VMEM
+slab bound, cap·block within the finalize bound, padded_keys < 2^24)
+raises, and callers that want the oracle ask for it with
+``use_kernel=False``.  The result matches
 ``core/delta.py:combine_route_scatter`` slot-for-slot (payloads to float
 addition order for "add").
 """
@@ -26,10 +26,21 @@ from repro.kernels.scatter_route.scatter_route import (DEFAULT_CHUNK,
                                                        scatter_route)
 
 
+def scatter_route_fits(snapshot, per_shard_capacity: int,
+                       combiner: str) -> bool:
+    """Whether the kernel takes this snapshot, segment capacity and
+    combiner."""
+    B = snapshot.block_size
+    return (combiner == "add" and snapshot.scheme == "block"
+            and B <= MAX_BLOCK
+            and per_shard_capacity * B <= MAX_MATCH_CELLS
+            and snapshot.padded_keys <= MAX_EXACT_KEY)
+
+
 def scatter_route_deltas(db: DeltaBuffer, owners: jax.Array,
                          num_shards: int, per_shard_capacity: int,
                          combiner: str = "add", *, snapshot,
-                         use_kernel: bool = True, interpret: bool = True
+                         interpret: bool, use_kernel: bool = True
                          ) -> DeltaBuffer:
     """Combine + route ``db`` into per-owner segments, sort-free.
 
@@ -39,6 +50,15 @@ def scatter_route_deltas(db: DeltaBuffer, owners: jax.Array,
     function of the key via ``snapshot`` (out-of-range owners drop the
     whole key).
     """
+    if use_kernel and not scatter_route_fits(snapshot, per_shard_capacity,
+                                             combiner):
+        raise ValueError(
+            f"scatter_route takes the 'add' combiner on a block snapshot "
+            f"with block_size <= {MAX_BLOCK}, cap·block <= "
+            f"{MAX_MATCH_CELLS} and padded_keys <= {MAX_EXACT_KEY}; got "
+            f"{combiner!r}, {snapshot.scheme!r}, block_size="
+            f"{snapshot.block_size}, cap={per_shard_capacity} — route this "
+            "buffer with the jnp path")
     if snapshot.scheme != "block":
         # (owner, local) slab addressing is only injective under the
         # block scheme; the hash scheme goes through the global-key slab
@@ -52,11 +72,7 @@ def scatter_route_deltas(db: DeltaBuffer, owners: jax.Array,
     mask = db.keys != PAD_KEY
     owners = jnp.where(mask, owners, S)
     local = snapshot.local_index(db.keys)
-    ok_kernel = (use_kernel and combiner == "add"
-                 and B <= MAX_BLOCK
-                 and per_shard_capacity * B <= MAX_MATCH_CELLS
-                 and snapshot.padded_keys <= MAX_EXACT_KEY)
-    if ok_kernel:
+    if use_kernel:
         keys_p = _pad_to(db.keys, DEFAULT_CHUNK, -1)
         pay_p = _pad_to(db.payload, DEFAULT_CHUNK, 0.0)
         loc_p = _pad_to(local, DEFAULT_CHUNK, -1)

@@ -36,14 +36,9 @@ JAX_MODES = ("off", "local", "distributed")
 
 
 def _enable_cpu_gloo() -> None:
-    """Cross-process CPU collectives need the gloo backend where the
-    config knob exists; older jaxlibs that lack it either default
-    correctly or fail loudly at the first collective."""
+    """Cross-process CPU collectives need the gloo backend."""
     import jax
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — knob absent on this jax
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def _report_distributed(args) -> dict:

@@ -94,14 +94,9 @@ def free_port() -> int:
 
 
 def _enable_cpu_gloo() -> None:
-    """Cross-process CPU collectives need the gloo backend where the
-    config knob exists; older jaxlibs that lack it either default
-    correctly or fail loudly at the first collective."""
+    """Cross-process CPU collectives need the gloo backend."""
     import jax
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — knob absent on this jax
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def initialize_from_env(env=None):
@@ -196,6 +191,10 @@ class Cluster:
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH")
             else "")
         if self.jax_mode != "off":
+            # Workers are the CPU failure harness: pinned to the CPU so a
+            # coordinator that holds an accelerator never has a child
+            # contend for it.
+            env["JAX_PLATFORMS"] = "cpu"
             env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count"
                                 f"={self.devices_per_worker}")
         env.update(self.extra_env)
@@ -636,6 +635,7 @@ def selftest(num_workers: int = 4, devices_per_worker: int = 2,
     env = dict(os.environ)
     env["PYTHONPATH"] = _src_root() + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count"
                         f"={devices_per_worker}")
     procs = []

@@ -9,11 +9,7 @@ all-reduce crosses DCN); "data" is in-pod DP/FSDP; "model" is TP/EP.
 REX analytics shards its key space over the FLATTENED device list (a
 partition snapshot has no TP notion) — ``flat_mesh`` provides that view.
 
-Compatibility floor: ``jax.sharding.AxisType`` only exists from jax 0.5.x;
-on older jax (0.4.37 ships ``jax.make_mesh`` but no axis types) every mesh
-here is built without the ``axis_types`` keyword — the default is Auto
-everywhere, which is exactly what these helpers request when the enum
-exists, so behaviour is identical on both sides of the floor.
+Every mesh here asks for Auto axis types explicitly.
 """
 from __future__ import annotations
 
@@ -24,24 +20,20 @@ import numpy as np
 import jax
 
 
-def _axis_types_kw(num_axes: int) -> dict:
-    """``axis_types=(Auto, ...)`` when this jax has the enum, else {}."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
+def _auto(num_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * num_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests, small-scale drivers)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_types_kw(len(axes)))
+                         axis_types=_auto(len(axes)))
 
 
 def flat_mesh(num_devices: int | None = None, axis: str = "shards", *,
@@ -69,12 +61,9 @@ def flat_mesh(num_devices: int | None = None, axis: str = "shards", *,
             raise ValueError("flat_mesh: empty device list")
         arr = np.empty(len(devices), dtype=object)
         arr[:] = devices
-        try:
-            return jax.sharding.Mesh(arr, (axis,), **_axis_types_kw(1))
-        except TypeError:      # Mesh() predating the axis_types keyword
-            return jax.sharding.Mesh(arr, (axis,))
+        return jax.sharding.Mesh(arr, (axis,), axis_types=_auto(1))
     n = num_devices or len(jax.devices())
-    return jax.make_mesh((n,), (axis,), **_axis_types_kw(1))
+    return jax.make_mesh((n,), (axis,), axis_types=_auto(1))
 
 
 # ---------------------------------------------------------------------------

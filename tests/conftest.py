@@ -1,98 +1,12 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — smoke tests and benches
 must see 1 device; only launch/dryrun.py forces 512 virtual devices.
-
-Also provides a minimal fallback for ``hypothesis`` so the suite collects
-and runs when the real package is absent (see requirements-dev.txt): the
-shim draws a small, deterministic sample from each strategy instead of
-doing real property search.  Install ``hypothesis`` for full coverage.
 """
 import gc
-import sys
-import types
 
 import numpy as np
 import pytest
 
 import jax
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # pragma: no cover - exercised when hypothesis missing
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def draw(self, rng):
-            return self._draw(rng)
-
-    # Parameter names mirror the real hypothesis API so both positional
-    # and keyword call styles behave identically under the shim.
-    def _integers(min_value=0, max_value=1 << 30):
-        return _Strategy(
-            lambda rng: int(rng.integers(min_value, max_value + 1)))
-
-    def _floats(min_value=0.0, max_value=1.0, **_kw):
-        return _Strategy(
-            lambda rng: float(rng.uniform(min_value, max_value)))
-
-    def _booleans():
-        return _Strategy(lambda rng: bool(rng.integers(0, 2)))
-
-    def _sampled_from(seq):
-        seq = list(seq)
-        return _Strategy(lambda rng: seq[int(rng.integers(0, len(seq)))])
-
-    def _lists(elem, min_size=0, max_size=10, **_kw):
-        def draw(rng):
-            size = int(rng.integers(min_size, max_size + 1))
-            return [elem.draw(rng) for _ in range(size)]
-        return _Strategy(draw)
-
-    def _given(**strategies):
-        def decorate(fn):
-            import functools
-            import inspect
-
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_shim_max_examples", 10)
-                rng = np.random.default_rng(0)
-                for _ in range(n):
-                    drawn = {k: s.draw(rng) for k, s in strategies.items()}
-                    fn(*args, **kwargs, **drawn)
-
-            # Drop drawn params from the visible signature so pytest does
-            # not look for fixtures named after them.
-            sig = inspect.signature(fn)
-            remaining = [p for name, p in sig.parameters.items()
-                         if name not in strategies]
-            wrapper.__signature__ = sig.replace(parameters=remaining)
-            wrapper._shim_max_examples = 10
-            return wrapper
-        return decorate
-
-    def _settings(max_examples=10, **_kw):
-        def decorate(fn):
-            fn._shim_max_examples = max_examples
-            return fn
-        return decorate
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.floats = _floats
-    _st.booleans = _booleans
-    _st.sampled_from = _sampled_from
-    _st.lists = _lists
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.__is_shim__ = True
-
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
-
 
 # Every module's fixpoints stay alive in jax's global jit caches even
 # after the module's fixtures are torn down; by the tail of the suite the

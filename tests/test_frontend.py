@@ -258,7 +258,7 @@ def _ulp_close(a, b, ulps=1):
 
 
 class TestBitIdentity:
-    @settings(max_examples=4)
+    @settings(max_examples=4, deadline=None)
     @given(seed=st.integers(0, 1000), deg=st.sampled_from([4.0, 12.0]))
     def test_pagerank(self, seed, deg):
         indptr, indices = make_powerlaw_graph(512, avg_degree=deg, seed=seed)
@@ -273,7 +273,7 @@ class TestBitIdentity:
         assert np.array_equal(np.asarray(rg.stats.delta_counts),
                               np.asarray(rw.stats.delta_counts))
 
-    @settings(max_examples=4)
+    @settings(max_examples=4, deadline=None)
     @given(seed=st.integers(0, 1000), source=st.integers(0, 511))
     def test_sssp(self, seed, source):
         indptr, indices = make_powerlaw_graph(512, avg_degree=8.0, seed=seed)
@@ -284,7 +284,7 @@ class TestBitIdentity:
         want, _ = sssp.run(g, snap, source=source, max_iters=80)
         assert bool(jnp.all(got == want))
 
-    @settings(max_examples=4)
+    @settings(max_examples=4, deadline=None)
     @given(seed=st.integers(0, 1000))
     def test_connected_components(self, seed):
         indptr, indices = make_powerlaw_graph(512, avg_degree=6.0, seed=seed)
@@ -317,7 +317,7 @@ class TestBitIdentity:
 # ---------------------------------------------------------------------------
 
 class TestReachability:
-    @settings(max_examples=5)
+    @settings(max_examples=5, deadline=None)
     @given(seed=st.integers(0, 1000), source=st.integers(0, 511))
     def test_matches_bfs_oracle(self, seed, source):
         n = 512

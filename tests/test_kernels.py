@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref oracle."""
+"""Per-kernel shape/dtype sweeps: Pallas (interpret mode off the chip) vs
+ref oracle."""
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -6,6 +7,7 @@ from hypothesis import given, settings, strategies as st
 import jax.numpy as jnp
 
 from repro.data.graphs import make_powerlaw_graph
+from repro.kernels import interpret_mode
 from repro.kernels.delta_route import (delta_route, delta_route_ref,
                                        route_deltas)
 from repro.kernels.scatter_route import (scatter_route, scatter_route_ref,
@@ -74,7 +76,7 @@ class TestDeltaRoute:
         owners = np.where(keys >= 0, keys % shards, shards).astype(np.int32)
         args = (jnp.asarray(keys), jnp.asarray(pay), jnp.asarray(ann),
                 jnp.asarray(owners), shards, cap)
-        out_k = delta_route(*args)
+        out_k = delta_route(*args, interpret=interpret_mode())
         out_r = delta_route_ref(*args)
         for a, b in zip(out_k, out_r):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -95,7 +97,8 @@ class TestDeltaRoute:
         owners = jnp.where(db.keys >= 0, db.keys % shards, shards)
         ref = route_by_owner(db, owners, shards, cap)
         for use_kernel in (False, True):
-            got = route_deltas(db, owners, shards, cap,
+            got = route_deltas(db, owners, shards, cap, max_key=500,
+                               interpret=interpret_mode(),
                                use_kernel=use_kernel)
             np.testing.assert_array_equal(np.asarray(ref.keys),
                                           np.asarray(got.keys))
@@ -112,7 +115,8 @@ class TestDeltaRoute:
         db = DeltaBuffer(keys=keys, payload=jnp.ones((8, 1)),
                          ann=jnp.zeros(8, jnp.int8), count=jnp.asarray(8),
                          overflowed=jnp.asarray(False))
-        out = route_deltas(db, jnp.zeros(8, jnp.int32), 2, 4)
+        out = route_deltas(db, jnp.zeros(8, jnp.int32), 2, 4, max_key=8,
+                           interpret=interpret_mode())
         assert bool(out.overflowed) and int(out.count) == 4
 
 
@@ -129,7 +133,7 @@ class TestScatterRoute:
         local = np.where(keys >= 0, keys % block, -1).astype(np.int32)
         args = (jnp.asarray(keys), jnp.asarray(pay), jnp.asarray(local),
                 jnp.asarray(owners), shards, block, cap)
-        out_k = scatter_route(*args)
+        out_k = scatter_route(*args, interpret=interpret_mode())
         out_r = scatter_route_ref(*args, combiner="add")
         np.testing.assert_array_equal(np.asarray(out_k[0]),
                                       np.asarray(out_r[0]))
@@ -159,9 +163,17 @@ class TestScatterRoute:
         snap = PartitionSnapshot(n_keys=keyspace, num_shards=shards)
         owners = snap.owner_of(db.keys)
         ref = combine_route(db, owners, shards, cap, combiner)
-        for use_kernel in (False, True):
+        if combiner != "add":
+            # The kernel implements "add" only, and never hands another
+            # combiner to the oracle behind the caller's back.
+            with pytest.raises(ValueError, match="jnp path"):
+                scatter_route_deltas(db, owners, shards, cap, combiner,
+                                     snapshot=snap,
+                                     interpret=interpret_mode())
+        for use_kernel in (False, True) if combiner == "add" else (False,):
             got = scatter_route_deltas(db, owners, shards, cap, combiner,
                                        snapshot=snap,
+                                       interpret=interpret_mode(),
                                        use_kernel=use_kernel)
             np.testing.assert_array_equal(np.asarray(ref.keys),
                                           np.asarray(got.keys))

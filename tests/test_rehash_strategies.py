@@ -19,7 +19,8 @@ from repro.algorithms import pagerank, sssp
 from repro.core.delta import (ANN_ADJUST, PAD_KEY, DeltaBuffer,
                               combine_route, combine_route_scatter)
 from repro.core.engine import ShardedExecutor
-from repro.core.fixpoint import ROUTE_SCATTER, ROUTE_SORT
+from repro.core.fixpoint import (ROUTE_SCATTER, ROUTE_SCATTER_KERNEL,
+                                 ROUTE_SORT, ROUTE_SORT_KERNEL)
 from repro.core.partition import PartitionSnapshot
 from repro.data.graphs import make_powerlaw_graph, shard_csr
 
@@ -175,9 +176,11 @@ def test_sssp_scatter_bit_identical(graph):
 
 
 def test_pallas_route_in_loop_matches_jnp(graph):
-    """use_pallas_route dispatches the delta_route / scatter_route kernels
-    inside the stratum body (interpret mode on CPU); SSSP's min combiner
-    makes both kernel paths bit-exact against the jnp engine."""
+    """use_pallas_route dispatches the delta_route kernel inside the
+    stratum body (interpret mode on CPU) and is bit-exact against the jnp
+    engine under SSSP's min combiner.  The scatter kernel implements
+    "add" only, so the scatter strategy routes SSSP through jnp, and the
+    recorded route codes say so."""
     snap, g = graph
     caps = dict(edge_capacity=2048, src_capacity=snap.block_size)
 
@@ -199,3 +202,35 @@ def test_pallas_route_in_loop_matches_jnp(graph):
         np.testing.assert_array_equal(
             np.asarray(r0.stats.rehash_bytes),
             np.asarray(r1.stats.rehash_bytes), err_msg=strat)
+        routes = np.asarray(r1.stats.routes)[:int(r1.stats.iterations)]
+        expect = ROUTE_SORT_KERNEL if strat == "sort" else ROUTE_SCATTER
+        sparse = routes >= 0
+        assert sparse.any() and np.all(routes[sparse] == expect), strat
+
+
+@pytest.mark.parametrize("strat,kernel_code", [
+    ("sort", ROUTE_SORT_KERNEL), ("scatter", ROUTE_SCATTER_KERNEL)])
+def test_pallas_route_pagerank_kernel_path(graph, strat, kernel_code):
+    """PageRank's "add" combiner is within both kernels' bounds: every
+    sparse stratum records the kernel's route code, delta counts equal
+    the jnp engine's, and values agree to float addition order."""
+    snap, g = graph
+    caps = dict(edge_capacity=2048, src_capacity=snap.block_size)
+
+    def ex(**kw):
+        return ShardedExecutor(snapshot=snap, seg_capacity=2048,
+                               edge_capacity=2048,
+                               src_capacity=snap.block_size,
+                               route_strategy=strat, **kw)
+
+    p0, r0 = pagerank.run(g, snap, mode="delta", threshold=1e-3,
+                          executor=ex(), **caps)
+    p1, r1 = pagerank.run(g, snap, mode="delta", threshold=1e-3,
+                          executor=ex(use_pallas_route=True), **caps)
+    routes = np.asarray(r1.stats.routes)[:int(r1.stats.iterations)]
+    sparse = routes >= 0
+    assert sparse.any() and np.all(routes[sparse] == kernel_code)
+    np.testing.assert_array_equal(np.asarray(r0.stats.delta_counts),
+                                  np.asarray(r1.stats.delta_counts))
+    np.testing.assert_allclose(np.asarray(p1), np.asarray(p0), rtol=1e-6,
+                               atol=1e-7)
