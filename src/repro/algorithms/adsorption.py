@@ -25,6 +25,7 @@ from repro.core.engine import DeltaAlgorithm, ShardedExecutor
 from repro.core.fixpoint import FixpointResult
 from repro.core.partition import PartitionSnapshot
 from repro.data.graphs import CSRGraph
+from repro.obs.trace import span
 
 INJECTION = 0.25
 
@@ -112,6 +113,7 @@ def initial_state(snapshot: PartitionSnapshot, seeds: jax.Array
     return AdsorptionState(acc=z, sent=z, seed=seed)
 
 
+@span("rex.adsorption.run")
 def run(graph_sharded: CSRGraph, snapshot: PartitionSnapshot,
         seeds: jax.Array, mode: str = "delta", threshold: float = 1e-2,
         max_iters: int = 50, executor: Optional[ShardedExecutor] = None,

@@ -20,6 +20,7 @@ from repro.core.engine import DeltaAlgorithm, ShardedExecutor
 from repro.core.fixpoint import FixpointResult
 from repro.core.partition import PartitionSnapshot
 from repro.data.graphs import CSRGraph
+from repro.obs.trace import span
 
 
 class CCState(NamedTuple):
@@ -80,6 +81,7 @@ def initial_state(snapshot: PartitionSnapshot) -> CCState:
     return CCState(label=ids, sent=jnp.full((S, block), jnp.inf, jnp.float32))
 
 
+@span("rex.connected_components.run")
 def run(graph_sharded: CSRGraph, snapshot: PartitionSnapshot,
         mode: str = "delta", max_iters: int = 80,
         executor: Optional[ShardedExecutor] = None,

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.fixpoint import (FixpointResult, StratumOutcome, run_strata)
+from repro.obs.trace import span
 
 BYTES_PER_DELTA = 16          # cid:int32 + x:f32 + y:f32 + count:f32
 BYTES_PER_POINT_RECORD = 16   # what a MapReduce shuffle ships per point
@@ -129,6 +130,7 @@ def make_stratum(points_sharded: jax.Array, k: int, mode: str = "delta",
     return stratum
 
 
+@span("rex.kmeans.run")
 def run(points_sharded: jax.Array, init_centroids: jax.Array,
         mode: str = "delta", max_iters: int = 60,
         valid: Optional[jax.Array] = None) -> tuple[

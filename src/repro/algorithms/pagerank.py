@@ -30,6 +30,7 @@ from repro.core.engine import DeltaAlgorithm, ShardedExecutor
 from repro.core.fixpoint import FixpointResult
 from repro.core.partition import PartitionSnapshot, shard_dense_state
 from repro.data.graphs import CSRGraph
+from repro.obs.trace import span
 
 DAMPING = 0.85
 BASE = 0.15
@@ -112,6 +113,7 @@ def initial_state(snapshot: PartitionSnapshot) -> PRState:
     return PRState(acc=z, sent=z)
 
 
+@span("rex.pagerank.run")
 def run(graph_sharded: CSRGraph, snapshot: PartitionSnapshot,
         mode: str = "delta", threshold: float = 1e-3, max_iters: int = 60,
         executor: Optional[ShardedExecutor] = None,

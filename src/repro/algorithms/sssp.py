@@ -27,6 +27,7 @@ from repro.core.engine import DeltaAlgorithm, ShardedExecutor
 from repro.core.fixpoint import FixpointResult
 from repro.core.partition import PartitionSnapshot
 from repro.data.graphs import CSRGraph
+from repro.obs.trace import span
 
 INF = jnp.float32(jnp.inf)
 
@@ -101,6 +102,7 @@ def initial_state(snapshot: PartitionSnapshot, source: int = 0) -> SPState:
     return SPState(dist=dist, sent=sent)
 
 
+@span("rex.sssp.run")
 def run(graph_sharded: CSRGraph, snapshot: PartitionSnapshot,
         source: int = 0, mode: str = "delta", max_iters: int = 80,
         executor: Optional[ShardedExecutor] = None,

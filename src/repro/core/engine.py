@@ -41,6 +41,7 @@ from repro.core.fixpoint import (ROUTE_SCATTER, ROUTE_SCATTER_KERNEL,
                                  FixpointResult, StratumOutcome, run_strata,
                                  with_explicit_condition)
 from repro.core.partition import PartitionSnapshot
+from repro.obs.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -177,13 +178,20 @@ class ShardedExecutor:
     combiner the kernel lacks, routes through jnp; each stratum's
     ``route`` code records which implementation ran (``route_code``).
 
-    Observability: an attached ``tracer`` (``repro.obs.Tracer``) records a
-    per-stratum probe from inside the compiled loop —
-    ``jax.debug.callback`` survives ``lax.while_loop`` and ``shard_map``,
-    so arrival-time deltas measure per-stratum (per-shard under
-    shard_map) wall clock along with tier/route/emitted/rehash-bytes.
-    ``tracer=None`` (the default) emits no callbacks at all: the traced
-    computation is exactly the uninstrumented one, bit-identical.
+    Observability: each stratum runs under ``jax.named_scope``s —
+    ``rex.select`` (active sources, rung choice), ``rex.rung<k>`` or
+    ``rex.dense`` (the body), inside it ``rex.emit``, ``rex.route`` and
+    ``rex.apply``, and ``rex.loop`` (the loop's condition and counts) —
+    which are HLO metadata only, so a profiler trace attributes device
+    time to each layer.  ``run``, ``resume`` and ``precompile`` open
+    ``rex.executor.*`` / ``rex.precompile`` host spans
+    (``repro.obs.trace.span``).  An attached ``tracer``
+    (``repro.obs.Tracer``) also records a per-stratum probe from inside
+    the compiled loop — ``jax.debug.callback`` survives
+    ``lax.while_loop`` and ``shard_map`` — whose duration is the gap
+    between host arrivals (per shard under shard_map), not device time,
+    along with tier/route/emitted/rehash-bytes.  ``tracer=None`` (the
+    default) emits no callbacks at all: outputs are bit-identical.
 
     ``route_strategy="measured"`` swaps the "auto" static cost model for
     a measured per-rung dispatch table (``route_table``, built by
@@ -448,22 +456,25 @@ class ShardedExecutor:
         count and the immutable set as arguments: a second call with the
         same shapes and the same executor, algorithm, mode, ``max_iters``
         and ``explicit_cond`` reuses the compiled loop."""
-        if mode not in ("delta", "nodelta"):
-            raise ValueError(mode)
-        self._check_backend()
-        if self.tracer is not None:
-            # Anchor shard timelines at dispatch so the first stratum's
-            # measured duration excludes host setup (eager calls; under
-            # an enclosing jit this runs once at trace time, which only
-            # shifts the first measured stratum).
-            self.tracer.mark_shards(self.snapshot.num_shards)
-        return _fixpoint(
-            state0, jnp.asarray(live0, jnp.int32), immutable,
-            executor=self,
-            observers=(_ByIdentity(self.tracer),
-                       _ByIdentity(self.route_table)),
-            algo=algo, mode=mode, max_iters=max_iters,
-            explicit_cond=explicit_cond)
+        with span("rex.executor.run", self.tracer):
+            with span("rex.executor.prepare", self.tracer):
+                if mode not in ("delta", "nodelta"):
+                    raise ValueError(mode)
+                self._check_backend()
+                if self.tracer is not None:
+                    # Anchor shard timelines at dispatch so the first
+                    # stratum's probe excludes host setup (eager calls;
+                    # under an enclosing jit this runs once at trace
+                    # time, which only shifts the first probe).
+                    self.tracer.mark_shards(self.snapshot.num_shards)
+                live0 = jnp.asarray(live0, jnp.int32)
+            with span("rex.executor.dispatch", self.tracer):
+                return _fixpoint(
+                    state0, live0, immutable, executor=self,
+                    observers=(_ByIdentity(self.tracer),
+                               _ByIdentity(self.route_table)),
+                    algo=algo, mode=mode, max_iters=max_iters,
+                    explicit_cond=explicit_cond)
 
     def precompile(self, algo: DeltaAlgorithm, state0, immutable,
                    max_iters: int, mode: str = "delta",
@@ -476,13 +487,14 @@ class ShardedExecutor:
         if mode not in ("delta", "nodelta"):
             raise ValueError(mode)
         self._check_backend()
-        return _fixpoint.lower(
-            state0, jax.ShapeDtypeStruct((), jnp.int32), immutable,
-            executor=self,
-            observers=(_ByIdentity(self.tracer),
-                       _ByIdentity(self.route_table)),
-            algo=algo, mode=mode, max_iters=max_iters,
-            explicit_cond=explicit_cond).compile()
+        with span("rex.precompile"):
+            return _fixpoint.lower(
+                state0, jax.ShapeDtypeStruct((), jnp.int32), immutable,
+                executor=self,
+                observers=(_ByIdentity(self.tracer),
+                           _ByIdentity(self.route_table)),
+                algo=algo, mode=mode, max_iters=max_iters,
+                explicit_cond=explicit_cond).compile()
 
     def _fixpoint_loop(self, algo: DeltaAlgorithm, state0, live0,
                        immutable, max_iters: int, mode: str,
@@ -562,9 +574,11 @@ class ShardedExecutor:
         views pay O(|repair|)-scaled sort/scatter cost instead of the full
         configured capacity.
         """
-        live0 = self.live_count(algo, warm_state, immutable)
-        return self.run(algo, warm_state, live0, immutable, max_iters,
-                        mode=mode, explicit_cond=explicit_cond)
+        with span("rex.executor.resume", self.tracer):
+            with span("rex.executor.prepare", self.tracer):
+                live0 = self.live_count(algo, warm_state, immutable)
+            return self.run(algo, warm_state, live0, immutable, max_iters,
+                            mode=mode, explicit_cond=explicit_cond)
 
     def make_stratum_fn(self, algo: DeltaAlgorithm, immutable,
                         mode: str = "delta",
@@ -663,78 +677,93 @@ class ShardedExecutor:
             # constant, made from the rung's static capacities.
             route_code = self.route_code(tier, combiner)
 
+            @jax.named_scope(f"rex.rung{tier_idx}")
             def sparse_body(state, stratum, active):
-                partial_state, outgoing = _over_shards(
-                    emit_fn, state, immutable, active, stratum, shard_ids,
-                    in_axes=(0, 0, 0, None, 0))
-                incoming, emitted = self.rehash_sparse_simulated(
-                    outgoing, seg_capacity=tier.seg, combiner=combiner,
-                    route=route_code)
-                new_state, next_active = _over_shards(
-                    algo.apply_sparse, partial_state, incoming, immutable,
-                    stratum, shard_ids, in_axes=(0, 0, 0, None, 0))
-                bytes_moved = emitted.astype(
-                    jnp.float32) * algo.bytes_per_delta
-                return new_state, StratumOutcome(
-                    live_count=jnp.sum(next_active),
-                    used_dense=jnp.asarray(False),
-                    rehash_bytes=bytes_moved, emitted=emitted,
-                    tier=jnp.asarray(tier_idx, jnp.int32),
-                    route=jnp.asarray(route_code, jnp.int32))
+                with jax.named_scope("rex.emit"):
+                    partial_state, outgoing = _over_shards(
+                        emit_fn, state, immutable, active, stratum,
+                        shard_ids, in_axes=(0, 0, 0, None, 0))
+                with jax.named_scope("rex.route"):
+                    incoming, emitted = self.rehash_sparse_simulated(
+                        outgoing, seg_capacity=tier.seg, combiner=combiner,
+                        route=route_code)
+                with jax.named_scope("rex.apply"):
+                    new_state, next_active = _over_shards(
+                        algo.apply_sparse, partial_state, incoming,
+                        immutable, stratum, shard_ids,
+                        in_axes=(0, 0, 0, None, 0))
+                with jax.named_scope("rex.loop"):
+                    bytes_moved = emitted.astype(
+                        jnp.float32) * algo.bytes_per_delta
+                    return new_state, StratumOutcome(
+                        live_count=jnp.sum(next_active),
+                        used_dense=jnp.asarray(False),
+                        rehash_bytes=bytes_moved, emitted=emitted,
+                        tier=jnp.asarray(tier_idx, jnp.int32),
+                        route=jnp.asarray(route_code, jnp.int32))
 
             return sparse_body
 
+        @jax.named_scope("rex.dense")
         def dense_body(state, stratum, active):
-            partial_state, contrib = _over_shards(
-                algo.dense_emit, state, immutable, stratum, shard_ids,
-                in_axes=(0, 0, None, 0))
-            incoming = self.rehash_dense_simulated(contrib, algo.combiner)
-            new_state, next_active = _over_shards(
-                algo.apply_dense, partial_state, incoming, immutable,
-                stratum, shard_ids, in_axes=(0, 0, 0, None, 0))
+            with jax.named_scope("rex.emit"):
+                partial_state, contrib = _over_shards(
+                    algo.dense_emit, state, immutable, stratum, shard_ids,
+                    in_axes=(0, 0, None, 0))
+            with jax.named_scope("rex.route"):
+                incoming = self.rehash_dense_simulated(contrib,
+                                                       algo.combiner)
+            with jax.named_scope("rex.apply"):
+                new_state, next_active = _over_shards(
+                    algo.apply_dense, partial_state, incoming, immutable,
+                    stratum, shard_ids, in_axes=(0, 0, 0, None, 0))
             n_padded = contrib.shape[1]
-            bytes_moved = jnp.asarray(
-                S * n_padded * algo.payload_width * 4, jnp.float32)
-            return new_state, StratumOutcome(
-                live_count=jnp.sum(next_active),
-                used_dense=jnp.asarray(True),
-                rehash_bytes=bytes_moved,
-                emitted=jnp.sum(active.astype(jnp.int32)),
-                tier=jnp.asarray(-1, jnp.int32),
-                route=jnp.asarray(-1, jnp.int32))
+            with jax.named_scope("rex.loop"):
+                bytes_moved = jnp.asarray(
+                    S * n_padded * algo.payload_width * 4, jnp.float32)
+                return new_state, StratumOutcome(
+                    live_count=jnp.sum(next_active),
+                    used_dense=jnp.asarray(True),
+                    rehash_bytes=bytes_moved,
+                    emitted=jnp.sum(active.astype(jnp.int32)),
+                    tier=jnp.asarray(-1, jnp.int32),
+                    route=jnp.asarray(-1, jnp.int32))
 
         bodies = [make_sparse_body(t, i) for i, t in enumerate(tiers)]
         bodies.append(dense_body)
 
         def stratum(state, stratum_idx):
-            active, est_edges = _over_shards(algo.active_fn, state,
-                                             immutable)
-            per_shard_src = jnp.sum(active.astype(jnp.int32), axis=1)
+            with jax.named_scope("rex.select"):
+                active, est_edges = _over_shards(algo.active_fn, state,
+                                                 immutable)
+                per_shard_src = jnp.sum(active.astype(jnp.int32), axis=1)
+                if mode != "nodelta":
+                    # Smallest rung whose budgets cover the exact
+                    # predicted sizes; tiers ascend, so "fits" is
+                    # monotone and the rung index is len(tiers) − (#rungs
+                    # that fit).  No rung fits -> dense body.  The seg
+                    # budget is guarded too: one shard's emission can land
+                    # entirely in one destination segment, so a rung with
+                    # seg < edge must also cover the edge count or deltas
+                    # would be silently dropped by the route.
+                    max_src = jnp.max(per_shard_src)
+                    max_edges = jnp.max(est_edges)
+                    fits = jnp.stack([(max_src <= t.src)
+                                      & (max_edges <= min(t.edge, t.seg))
+                                      for t in tiers])
+                    branch = len(tiers) - jnp.sum(fits.astype(jnp.int32))
             if mode == "nodelta":
                 new_state, outcome = dense_body(state, stratum_idx, active)
             else:
-                # Smallest rung whose budgets cover the exact predicted
-                # sizes; tiers ascend, so "fits" is monotone and the rung
-                # index is len(tiers) − (#rungs that fit).  No rung fits
-                # -> dense body.  The seg budget is guarded too: one
-                # shard's emission can land entirely in one destination
-                # segment, so a rung with seg < edge must also cover the
-                # edge count or deltas would be silently dropped by the
-                # route.
-                max_src = jnp.max(per_shard_src)
-                max_edges = jnp.max(est_edges)
-                fits = jnp.stack([(max_src <= t.src)
-                                  & (max_edges <= min(t.edge, t.seg))
-                                  for t in tiers])
-                branch = len(tiers) - jnp.sum(fits.astype(jnp.int32))
                 new_state, outcome = jax.lax.switch(
                     branch, bodies, state, stratum_idx, active)
             if self.tracer is not None:
                 # One probe per stratum (all shards share the device);
-                # ordered keeps arrival deltas = stratum wall clock even
+                # ordered keeps arrival deltas in stratum order even
                 # inside the while_loop.
-                self.tracer.stratum_probe(stratum_idx, outcome,
-                                          ordered=True)
+                with jax.named_scope("rex.loop"):
+                    self.tracer.stratum_probe(stratum_idx, outcome,
+                                              ordered=True)
             return new_state, outcome
 
         return stratum
@@ -749,9 +778,10 @@ class ShardedExecutor:
 
         def stratum(carry, stratum_idx):
             state, imm = carry
-            shard_id = jax.lax.axis_index(axis)
-            active, est_edges = algo.active_fn(state, imm)
-            n_src = jnp.sum(active.astype(jnp.int32))
+            with jax.named_scope("rex.select"):
+                shard_id = jax.lax.axis_index(axis)
+                active, est_edges = algo.active_fn(state, imm)
+                n_src = jnp.sum(active.astype(jnp.int32))
 
             def make_sparse_body(tier: CapacityTier, tier_idx: int):
                 emit_fn = self._emit_fn(algo, tier)
@@ -759,40 +789,53 @@ class ShardedExecutor:
                 # function of static rung capacities).
                 route_code = self.route_code(tier, combiner)
 
+                @jax.named_scope(f"rex.rung{tier_idx}")
                 def sparse_body(st):
-                    partial_state, outgoing = emit_fn(
-                        st, imm, active, stratum_idx, shard_id)
-                    incoming, emitted = self.rehash_sparse_shard_map(
-                        outgoing, seg_capacity=tier.seg, combiner=combiner,
-                        route=route_code)
-                    new_state, next_active = algo.apply_sparse(
-                        partial_state, incoming, imm, stratum_idx, shard_id)
-                    return (new_state, imm), StratumOutcome(
-                        live_count=jax.lax.psum(next_active, axis),
-                        used_dense=jnp.asarray(False),
-                        rehash_bytes=emitted.astype(jnp.float32)
-                        * algo.bytes_per_delta,
-                        emitted=emitted,
-                        tier=jnp.asarray(tier_idx, jnp.int32),
-                        route=jnp.asarray(route_code, jnp.int32))
+                    with jax.named_scope("rex.emit"):
+                        partial_state, outgoing = emit_fn(
+                            st, imm, active, stratum_idx, shard_id)
+                    with jax.named_scope("rex.route"):
+                        incoming, emitted = self.rehash_sparse_shard_map(
+                            outgoing, seg_capacity=tier.seg,
+                            combiner=combiner, route=route_code)
+                    with jax.named_scope("rex.apply"):
+                        new_state, next_active = algo.apply_sparse(
+                            partial_state, incoming, imm, stratum_idx,
+                            shard_id)
+                    with jax.named_scope("rex.loop"):
+                        return (new_state, imm), StratumOutcome(
+                            live_count=jax.lax.psum(next_active, axis),
+                            used_dense=jnp.asarray(False),
+                            rehash_bytes=emitted.astype(jnp.float32)
+                            * algo.bytes_per_delta,
+                            emitted=emitted,
+                            tier=jnp.asarray(tier_idx, jnp.int32),
+                            route=jnp.asarray(route_code, jnp.int32))
 
                 return sparse_body
 
+            @jax.named_scope("rex.dense")
             def dense_body(st):
-                partial_state, contrib = algo.dense_emit(
-                    st, imm, stratum_idx, shard_id)
-                incoming = self.rehash_dense_shard_map(contrib, algo.combiner)
-                new_state, next_active = algo.apply_dense(
-                    partial_state, incoming, imm, stratum_idx, shard_id)
+                with jax.named_scope("rex.emit"):
+                    partial_state, contrib = algo.dense_emit(
+                        st, imm, stratum_idx, shard_id)
+                with jax.named_scope("rex.route"):
+                    incoming = self.rehash_dense_shard_map(contrib,
+                                                           algo.combiner)
+                with jax.named_scope("rex.apply"):
+                    new_state, next_active = algo.apply_dense(
+                        partial_state, incoming, imm, stratum_idx, shard_id)
                 n_padded = contrib.shape[0]
-                return (new_state, imm), StratumOutcome(
-                    live_count=jax.lax.psum(next_active, axis),
-                    used_dense=jnp.asarray(True),
-                    rehash_bytes=jnp.asarray(
-                        S * n_padded * algo.payload_width * 4, jnp.float32),
-                    emitted=jax.lax.psum(n_src, axis),
-                    tier=jnp.asarray(-1, jnp.int32),
-                    route=jnp.asarray(-1, jnp.int32))
+                with jax.named_scope("rex.loop"):
+                    return (new_state, imm), StratumOutcome(
+                        live_count=jax.lax.psum(next_active, axis),
+                        used_dense=jnp.asarray(True),
+                        rehash_bytes=jnp.asarray(
+                            S * n_padded * algo.payload_width * 4,
+                            jnp.float32),
+                        emitted=jax.lax.psum(n_src, axis),
+                        tier=jnp.asarray(-1, jnp.int32),
+                        route=jnp.asarray(-1, jnp.int32))
 
             if mode == "nodelta":
                 carry_out, outcome = dense_body(state)
@@ -801,12 +844,13 @@ class ShardedExecutor:
                 # the same rung (the dispatch feeds a collective-bearing
                 # branch).  The seg budget is guarded like the simulated
                 # backend.
-                max_src = jax.lax.pmax(n_src, axis)
-                max_edges = jax.lax.pmax(est_edges, axis)
-                fits = jnp.stack([(max_src <= t.src)
-                                  & (max_edges <= min(t.edge, t.seg))
-                                  for t in tiers])
-                branch = len(tiers) - jnp.sum(fits.astype(jnp.int32))
+                with jax.named_scope("rex.select"):
+                    max_src = jax.lax.pmax(n_src, axis)
+                    max_edges = jax.lax.pmax(est_edges, axis)
+                    fits = jnp.stack([(max_src <= t.src)
+                                      & (max_edges <= min(t.edge, t.seg))
+                                      for t in tiers])
+                    branch = len(tiers) - jnp.sum(fits.astype(jnp.int32))
                 bodies = [make_sparse_body(t, i)
                           for i, t in enumerate(tiers)]
                 bodies.append(dense_body)
@@ -816,8 +860,10 @@ class ShardedExecutor:
                 # shard id, so arrival times are per-shard stratum
                 # latencies.  Unordered — ordered effects cannot cross
                 # the shard_map collectives.
-                self.tracer.stratum_probe(stratum_idx, outcome,
-                                          shard_id=shard_id, ordered=False)
+                with jax.named_scope("rex.loop"):
+                    self.tracer.stratum_probe(stratum_idx, outcome,
+                                              shard_id=shard_id,
+                                              ordered=False)
             return carry_out, outcome
 
         return stratum

@@ -90,6 +90,7 @@ def run_strata(stratum_fn: Callable, state0, live0, max_iters: int,
         routes=jnp.full((max_iters,), -1, jnp.int32),
     )
 
+    @jax.named_scope("rex.loop")
     def cond_fn(carry):
         _, stratum, live, _ = carry
         return (stratum < max_iters) & (live > 0)
@@ -97,16 +98,19 @@ def run_strata(stratum_fn: Callable, state0, live0, max_iters: int,
     def body_fn(carry):
         state, stratum, _, stats = carry
         new_state, outcome = stratum_fn(state, stratum)
-        stats = StratumStats(
-            delta_counts=stats.delta_counts.at[stratum].set(outcome.emitted),
-            used_dense=stats.used_dense.at[stratum].set(outcome.used_dense),
-            rehash_bytes=stats.rehash_bytes.at[stratum].set(
-                outcome.rehash_bytes),
-            iterations=stratum + 1,
-            tiers=stats.tiers.at[stratum].set(outcome.tier),
-            routes=stats.routes.at[stratum].set(outcome.route),
-        )
-        return (new_state, stratum + 1, outcome.live_count, stats)
+        with jax.named_scope("rex.loop"):
+            stats = StratumStats(
+                delta_counts=stats.delta_counts.at[stratum].set(
+                    outcome.emitted),
+                used_dense=stats.used_dense.at[stratum].set(
+                    outcome.used_dense),
+                rehash_bytes=stats.rehash_bytes.at[stratum].set(
+                    outcome.rehash_bytes),
+                iterations=stratum + 1,
+                tiers=stats.tiers.at[stratum].set(outcome.tier),
+                routes=stats.routes.at[stratum].set(outcome.route),
+            )
+            return (new_state, stratum + 1, outcome.live_count, stats)
 
     carry = (state0, jnp.zeros((), jnp.int32), jnp.asarray(live0, jnp.int32),
              stats0)
@@ -192,8 +196,9 @@ def with_explicit_condition(stratum_fn: Callable, cond: Callable) -> Callable:
 
     def wrapped(state, stratum):
         new_state, outcome = stratum_fn(state, stratum)
-        keep = cond(new_state, state, stratum)
-        return new_state, outcome._replace(
-            live_count=jnp.where(keep, outcome.live_count, 0))
+        with jax.named_scope("rex.loop"):
+            keep = cond(new_state, state, stratum)
+            return new_state, outcome._replace(
+                live_count=jnp.where(keep, outcome.live_count, 0))
 
     return wrapped

@@ -24,6 +24,8 @@ import jax
 import numpy as np
 import jax.numpy as jnp
 
+from repro.obs.trace import span
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -81,6 +83,7 @@ def make_powerlaw_graph(n_vertices: int, avg_degree: float = 14.5,
     return indptr.astype(np.int64), dst
 
 
+@span("rex.shard_csr")
 def shard_csr(indptr: np.ndarray, indices: np.ndarray, num_shards: int,
               nnz_capacity: int | None = None) -> CSRGraph:
     """Partition a global CSR by source block into stacked per-shard CSR.

@@ -1,20 +1,30 @@
-"""Span-based tracer with an in-jit recording path.
+"""Host spans on the profiler's clock, and an in-jit probe path.
 
 Two recording surfaces share one event buffer:
 
   * **Host spans** — ``with tracer.span("view.refresh", view=name):`` for
     driver-side code (the resilient driver's stratum slices, view repairs,
-    replica writes).  Durations are real ``perf_counter`` intervals.
+    replica writes).  Durations are real ``perf_counter`` intervals, and
+    each span is also a ``jax.profiler.TraceAnnotation`` of the same name,
+    so a profiler trace shows it on the host plane beside the device's
+    operations.  Code with no tracer uses the module-level :func:`span`,
+    which always annotates and records only into a tracer it is given.
   * **In-jit probes** — ``tracer.stratum_probe(...)`` is called at *trace
     time* inside the engine's stratum bodies and inserts a
     ``jax.debug.callback`` whose operands are the stratum's outcome
     scalars.  The callback survives ``lax.while_loop``, ``lax.switch`` and
-    ``shard_map``: it fires on the host when the device reaches it, so the
-    arrival-time deltas are the measured per-stratum (and, under
-    shard_map, per-shard) wall clock.  Probes are data-dependent on the
-    outcome, purely observational, and emitted only when a tracer is
-    threaded in — ``tracer=None`` leaves the compiled computation
-    untouched (bit-identical, zero overhead).
+    ``shard_map`` and fires on the host when the device reaches it, so
+    each probe's duration is the gap between two host arrivals: host
+    time, not device time, and the callbacks themselves change the
+    compiled loop (an ordered callback syncs the host every stratum).
+    Probes are emitted only when a tracer is threaded in —
+    ``tracer=None`` leaves the compiled computation untouched.
+
+Device time per layer comes from elsewhere: the engine wraps each layer of
+a stratum in a ``jax.named_scope`` (``rex.select``, ``rex.rung<k>``,
+``rex.dense``, ``rex.emit``, ``rex.route``, ``rex.apply``, ``rex.loop``),
+which the compiled program carries as HLO metadata, so a profiler trace's
+operations can be attributed to them without any callback.
 
 Timestamps are ``perf_counter`` seconds relative to the tracer's epoch;
 ``obs/export.py`` converts to the Chrome-trace µs timeline.  Probe
@@ -23,9 +33,8 @@ order); shard_map uses unordered ones (ordered effects cannot cross a
 collective), so events carry their stratum index and the exporter orders
 by it, not by arrival.
 
-Measured latencies recorded here close the loop flagged in ROADMAP items
-1 and 5: :class:`MeasuredLatencies` is the per-shard timing source the
-resilient driver feeds to ``SpeculationPolicy`` when no synthetic
+:class:`MeasuredLatencies` is the per-shard timing source the resilient
+driver feeds to ``SpeculationPolicy`` when no synthetic
 ``latency_model`` is supplied, and ``obs/calibrate.py`` turns recorded
 per-rung route timings into the ``route_strategy="measured"`` table.
 """
@@ -81,13 +90,15 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, tid: str = "host", **attrs):
-        """Record a complete (ph "X") event around a host-side block.
+        """Record a complete (ph "X") event around a host-side block, and
+        annotate the block for the profiler under the same name.
         Yields the args dict — mutate it to attach results measured
         inside the span."""
         t0 = self._now()
         args = dict(attrs)
         try:
-            yield args
+            with jax.profiler.TraceAnnotation(name):
+                yield args
         finally:
             self._append({"name": name, "ph": "X", "ts": t0,
                           "dur": self._now() - t0, "tid": tid,
@@ -97,13 +108,6 @@ class Tracer:
         """Record a point event (recovery, rescale, speculation verdict)."""
         self._append({"name": name, "ph": "i", "ts": self._now(),
                       "tid": tid, "args": dict(attrs)})
-
-    def mark(self, tid: str = "host") -> None:
-        """Reset the duration anchor for ``tid`` — call right before
-        dispatching a computation whose probes should not absorb the
-        host time spent since the previous probe."""
-        with self._lock:
-            self._last_ts[tid] = self._now()
 
     def mark_shards(self, num_shards: int) -> None:
         """Anchor every shard timeline (and the aggregate "shards" row)
@@ -212,6 +216,20 @@ class Tracer:
             self.events.clear()
             self._last_ts.clear()
             self._stratum_times.clear()
+
+
+@contextlib.contextmanager
+def span(name: str, tracer: Optional[Tracer] = None, **attrs):
+    """A host span for code that may have no tracer: always a
+    ``jax.profiler.TraceAnnotation`` called ``name``, and a recorded
+    :meth:`Tracer.span` only when ``tracer`` is given.  Yields the span's
+    args dict either way.  Inserts nothing into a jitted program."""
+    if tracer is not None:
+        with tracer.span(name, **attrs) as args:
+            yield args
+    else:
+        with jax.profiler.TraceAnnotation(name):
+            yield dict(attrs)
 
 
 class MeasuredLatencies:

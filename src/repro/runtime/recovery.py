@@ -51,6 +51,7 @@ from repro.core.delta import PAD_KEY, DeltaBuffer
 from repro.core.fixpoint import (FixpointResult, StratumOutcome,
                                  stats_from_outcomes)
 from repro.core.partition import PartitionSnapshot
+from repro.obs.trace import span
 from repro.runtime.checkpoint import CheckpointCorruption, CheckpointManager
 from repro.runtime.elastic import migrate_route_buffers, remap_state
 from repro.runtime.retry import (IO_RETRYABLE, RecoveryExhausted, Retrier,
@@ -1035,12 +1036,9 @@ class ResilientDriver:
                 break
             self.step()
             if self.replicate:
-                if self.tracer is not None:
-                    with self.tracer.span("replicate", tid="driver",
-                                          stratum=self.stratum - 1) as a:
-                        a["bytes"] = self.chain.append(self._packed())
-                else:
-                    self.chain.append(self._packed())
+                with span("replicate", self.tracer, tid="driver",
+                          stratum=self.stratum - 1) as a:
+                    a["bytes"] = self.chain.append(self._packed())
             if self.mitigator is not None:
                 self._observe_straggler()
         result = FixpointResult(
