@@ -8,9 +8,11 @@ on each graph, and starts another round only while the rounds so far
 say it will end inside ``--seconds``; so it always holds whole rounds,
 at least one, and every run does the same mix of work.
 
-Every call's ranks are kept on the device and compared, after the
-window, with the float64 power iteration of ``bench/ref/pagerank.py``
-on that call's graph.
+As each call ends, its ranks and counts are copied to the host and its
+device arrays are dropped, so what the window leaves on the chip does
+not grow with the number of calls it completed.  After the window each
+call's ranks are compared with the float64 power iteration of
+``bench/ref/pagerank.py`` on that call's graph.
 """
 from __future__ import annotations
 
@@ -75,38 +77,50 @@ class Driver:
         jax.block_until_ready(call(jax.device_put(empty, place)))
 
     def window(self, seconds: float, spans) -> dict:
-        import jax
-
         t0 = time.perf_counter()
         rounds = 0
         while True:
             for i, graph in enumerate(self.graphs):
-                with spans("bench.fixpoint"):
-                    pr, res = self.call(graph)
-                    jax.block_until_ready((pr, res.stats))
-                self.results.append((i, pr, res.stats))
+                self.results.append(self._fixpoint(i, graph, spans))
             rounds += 1
             elapsed = time.perf_counter() - t0
             if elapsed * (rounds + 1) / rounds > seconds:
                 break
-        return {"fixpoint_s": elapsed / len(self.results)}
+        # The calls' own spans: the copies to the host between calls are
+        # the benchmark's, not the program's.
+        return {"fixpoint_s": sum(c["seconds"] for c in self.results)
+                / len(self.results)}
+
+    def _fixpoint(self, i: int, graph, spans) -> dict:
+        """One call on graph ``i``, from submit to ``block_until_ready``,
+        its answer and counts copied to the host once it has ended."""
+        import jax
+
+        with spans("bench.fixpoint"):
+            pr, res = self.call(graph)
+            jax.block_until_ready((pr, res.stats))
+        _, start, end = spans.spans[-1]     # the span this call closed
+        s = res.stats
+        pr, it, counts, dense = jax.device_get(
+            (pr, s.iterations, s.delta_counts, s.used_dense))
+        it = int(it)
+        return dict(graph=i, seconds=end - start,
+                    ranks=pr[:self.config["graph"]["vertices"]], strata=it,
+                    delta_counts=counts[:it], used_dense=dense[:it])
 
     def attempted(self) -> int:
         return len(self.results)
 
     def release(self) -> None:
-        """Move every windowed answer and count to the host and drop the
-        program's device state, before the reference runs."""
-        n = self.config["graph"]["vertices"]
-        self.answers, calls = [], []
-        for i, pr, stats in self.results:
-            it = int(stats.iterations)
-            self.answers.append((i, np.asarray(pr)[:n]))
-            calls.append(dict(
-                graph=i, strata=it,
-                delta_counts=np.asarray(stats.delta_counts)[:it].tolist(),
-                used_dense=np.asarray(stats.used_dense)[:it].tolist()))
-        self.stats = dict(vertices=n, shards=self.config["shards"],
+        """Gather the windowed answers and counts, already on the host,
+        and drop the program's device state, before the reference runs."""
+        self.answers = [(c["graph"], c["ranks"]) for c in self.results]
+        calls = [dict(graph=c["graph"], strata=c["strata"],
+                      delta_counts=c["delta_counts"].tolist(),
+                      used_dense=c["used_dense"].tolist())
+                 for c in self.results]
+        self.stats = dict(vertices=self.config["graph"]["vertices"],
+                          shards=self.config["shards"],
                           edges=[int(ip[-1]) for ip, _ in self.csr],
                           calls=calls)
         self.results = self.graphs = self.call = None

@@ -1,6 +1,7 @@
 """The benchmark's files hang together, a run refuses anything but a
 TPU it knows, and each driver runs a tiny cell end to end on the CPU
 and passes its comparison."""
+import itertools
 import json
 import os
 import re
@@ -8,13 +9,17 @@ import subprocess
 import sys
 import types
 
+import jax
+import numpy as np
 import pytest
 
 from bench import run
-from tiny import CELLS, execute_tiny
+from bench.drivers.common import Spans
+from tiny import CELLS, execute_tiny, tiny_cell
 
 BENCHMARK = run.load_json(run.ROOT, "BENCHMARK.json")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+ONE_CHIP_CELLS = [c for c in CELLS if run.load_cell(c)[1]["chips"] == 1]
 
 
 def test_benchmark_json_keys():
@@ -93,8 +98,7 @@ def test_unknown_device_kind_and_too_few_chips_are_refused(monkeypatch):
     assert len(devices) == 1 and peak["hbm_bytes_per_s"] == 819e9
 
 
-@pytest.mark.parametrize("cell", [c for c in CELLS
-                                  if run.load_cell(c)[1]["chips"] == 1])
+@pytest.mark.parametrize("cell", ONE_CHIP_CELLS)
 def test_driver_runs_a_tiny_cell_and_is_correct(cell):
     result = execute_tiny(cell)
     assert result["correct"], result
@@ -104,6 +108,68 @@ def test_driver_runs_a_tiny_cell_and_is_correct(cell):
     assert all(v["value"] > 0 for k, v in result["metrics"].items()
                if k != "peak_hbm_gb")
     assert list(result)[-1] == "checks"
+
+
+@pytest.mark.parametrize("cell", ONE_CHIP_CELLS)
+def test_window_keeps_host_copies_and_times_only_the_calls(cell,
+                                                           monkeypatch):
+    """After a window of three rounds the driver keeps no device array,
+    so what the window leaves on the chip does not grow with its calls
+    (the CPU reports no peak memory); ``fixpoint_s`` is the calls'
+    ``bench.fixpoint`` spans over the calls."""
+    workload, config = tiny_cell(cell)
+    module = run.load_module(os.path.join(run.BENCH, "drivers",
+                                          workload["driver"] + ".py"))
+    driver = module.Driver(config, workload, 2**31 + 29, jax.devices()[:1])
+    driver.setup()
+    # The round rule reads a clock that ticks once a round: three rounds.
+    monkeypatch.setattr(module, "time", types.SimpleNamespace(
+        perf_counter=itertools.count().__next__))
+    spans = Spans()
+    measured = driver.window(3.5, spans)
+    calls = driver.attempted()
+    assert calls == 3 * workload["graphs"]
+    leaves = jax.tree_util.tree_leaves(driver.results)
+    assert all(isinstance(x, (int, float, np.ndarray)) for x in leaves)
+    assert not any(isinstance(x, jax.Array) for x in leaves)
+    timed = [e - s for name, s, e in spans.spans if name == "bench.fixpoint"]
+    assert len(timed) == calls
+    assert measured["fixpoint_s"] == pytest.approx(sum(timed) / calls,
+                                                   rel=1e-12)
+    driver.release()
+    kept = jax.tree_util.tree_leaves((driver.answers, driver.stats))
+    assert not any(isinstance(x, jax.Array) for x in kept)
+
+
+@pytest.mark.parametrize("cell", ONE_CHIP_CELLS)
+def test_memory_probe_reads_the_device_after_each_call(cell):
+    """``bench/memory_probe.py`` reads the device after set-up, after each
+    windowed call, after the window and after ``release()``.  The CPU
+    has no counts, so a stand-in device numbers its readings."""
+    probe = run.load_module(os.path.join(run.BENCH, "memory_probe.py"))
+    workload, config = tiny_cell(cell)
+    driver = run.load_module(os.path.join(
+        run.BENCH, "drivers", workload["driver"] + ".py")).Driver(
+        config, workload, 2**31 + 31, jax.devices()[:1])
+    reads = itertools.count()
+    device = types.SimpleNamespace(memory_stats=lambda: dict(
+        bytes_in_use=next(reads), peak_bytes_in_use=0))
+    out = probe.probe(driver, device, 0.0)
+    calls = out["calls"]
+    assert calls == workload["graphs"]
+    assert [m[0] for m in out["after_each_call"]] == list(range(2, 2 + calls))
+    assert out["window"][0] == 2 + calls and out["release"][0] == 3 + calls
+    assert out["pr_max_abs_err"] <= workload["limits"]["pr_max_abs_err"]
+
+
+def test_a_cpu_memory_probe_exits_nonzero_and_prints_no_result():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(run.BENCH, "memory_probe.py"),
+         "--workload", CELLS[0], "--seed", "3", "--seconds", "1"],
+        cwd=run.ROOT, env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 2
+    assert "{" not in proc.stdout
 
 
 def test_shard_map_cell_runs_on_four_virtual_devices():
